@@ -95,7 +95,8 @@ from repro.fed.fleet.scenarios import SCENARIOS, build_scenario, run_scenario
 from repro.fed.fleet.workloads import WORKLOADS, client_sizes, get_workload
 from repro.fed.simulator import straggler_deadline
 from repro.models.small import LogisticRegression
-from repro.utils.xla_env import forced_host_device_env
+from repro.utils.xla_env import (enable_compile_cache,
+                                 forced_host_device_env)
 
 SWEEP_SCENARIOS = ("uniform", "pareto", "diurnal", "flash_crowd",
                    "device_classes")
@@ -1091,7 +1092,21 @@ def main(argv=None) -> int:
         os.path.dirname(os.path.abspath(__file__)), "BENCH_fleet.json"))
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    backend = jax.default_backend()
+    if (args.selection_memory or args.device_sweep) and backend != "cpu":
+        # these sections measure the CPU host in forced-device children;
+        # under a parent that holds an accelerator they would run on the
+        # CPU without saying so
+        ap.error(f"--selection-memory and --device-sweep measure the CPU "
+                 f"host in forced-device subprocesses, but this process "
+                 f"runs on {backend!r}; run them with JAX_PLATFORMS=cpu")
 
+    if args.sharded_worker or args.selection_memory_worker:
+        # workers time a cold first solve and read VmHWM across its
+        # compile: a persistent cache warmed by an earlier run would hide
+        # that compile, so they keep it off even where
+        # JAX_COMPILATION_CACHE_DIR is set
+        jax.config.update("jax_enable_compilation_cache", False)
     if args.sharded_worker:
         sharded_worker(args.clients or 512, args.epochs or 3,
                        args.batch_size, args.seed, parity=args.parity)
@@ -1105,8 +1120,11 @@ def main(argv=None) -> int:
     n_clients = args.clients or 1024
     epochs = args.epochs or (2 if args.smoke else 3)
     use_kernel = {"auto": None, "on": True, "off": False}[args.use_kernel]
+    # with the persistent cache on, the parent's *cold_wall_s include
+    # compilation only where the cache does not hold the program yet
+    enable_compile_cache()
     report = {"mode": "smoke" if args.smoke else "full",
-              "backend": jax.default_backend()}
+              "backend": backend, "persistent_compile_cache": True}
     ok = True
 
     if not args.skip_engine:

@@ -38,13 +38,31 @@ per sweep) as the measured A/B baseline for
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ref import F32
+
 BIG = 1e30
+
+# Ties: two candidates whose costs agree to float32 rounding are an
+# equal-cost tie (a 2-point cluster's two members, a symmetric BUILD
+# pick), and which one argmin returns then depends on reduction order —
+# kernel vs jnp, vmapped lane vs single client.  The on-device solvers
+# break such ties to the lowest index among the candidates within
+# TIE_RTOL of the best (relative to the lane's objective scale), so every
+# execution path of the same instance takes the same BUILD and SWAP
+# steps.  The f64 numpy oracle breaks exact ties the same way.
+TIE_RTOL = 1e-5
+# A swap must lower the objective by more than SWAP_RTOL of it.  Δ sums
+# hundreds of float32 distances, each off by up to ~sqrt(eps)·‖x‖ where
+# the norm expansion cancels; an absolute threshold below that noise
+# lets the sweep take phantom "improvements" and cycle until max_sweeps,
+# ending wherever the noise of that execution path leaves it.
+SWAP_RTOL = 1e-5
 
 
 class KMedoidsResult(NamedTuple):
@@ -72,6 +90,32 @@ def _build_numpy(D: np.ndarray, k: int) -> np.ndarray:
     return medoids
 
 
+def swap_deltas_numpy(D: np.ndarray, medoids: np.ndarray
+                      ) -> Tuple[np.ndarray, float]:
+    """FasterPAM's swap table in float64, and the objective.
+
+    ``delta[j, l]`` is the change of Σ_i min_s D[i, s] when medoid slot
+    ``l`` is replaced by point ``j`` (BIG where ``j`` is already a
+    medoid).  A medoid set is a fixed point of the swap search when no
+    entry is below −threshold."""
+    D = np.asarray(D, np.float64)
+    m, k = D.shape[0], len(medoids)
+    dm = D[:, medoids]                      # (m, k)
+    order = np.argsort(dm, axis=1)
+    n_idx = order[:, 0]                     # nearest medoid slot
+    d1 = dm[np.arange(m), n_idx]
+    d2 = dm[np.arange(m), order[:, 1]] if k > 1 else np.full(m, BIG)
+
+    A = np.minimum(D - d1[:, None], 0.0).sum(axis=0)              # (m,)
+    contrib = (np.minimum(D, d2[:, None]) - d1[:, None]
+               - np.minimum(D - d1[:, None], 0.0))                # (m_i, m_j)
+    B = np.zeros((m, k))
+    np.add.at(B.T, n_idx, contrib)  # B[j, l] = Σ_{i: n(i)=l} contrib[i, j]
+    delta = A[:, None] + B                                        # (m_j, k)
+    delta[medoids, :] = BIG  # cannot swap a medoid in
+    return delta, float(d1.sum())
+
+
 def kmedoids_numpy(D: np.ndarray, k: int, max_sweeps: int = 100
                    ) -> KMedoidsResult:
     D = np.asarray(D, np.float64)
@@ -80,19 +124,7 @@ def kmedoids_numpy(D: np.ndarray, k: int, max_sweeps: int = 100
     medoids = _build_numpy(D, k)
 
     for _ in range(max_sweeps):
-        dm = D[:, medoids]                      # (m, k)
-        order = np.argsort(dm, axis=1)
-        n_idx = order[:, 0]                     # nearest medoid slot
-        d1 = dm[np.arange(m), n_idx]
-        d2 = dm[np.arange(m), order[:, 1]] if k > 1 else np.full(m, BIG)
-
-        A = np.minimum(D - d1[:, None], 0.0).sum(axis=0)          # (m,)
-        contrib = (np.minimum(D, d2[:, None]) - d1[:, None]
-                   - np.minimum(D - d1[:, None], 0.0))            # (m_i, m_j)
-        B = np.zeros((m, k))
-        np.add.at(B.T, n_idx, contrib)  # B[j, l] = Σ_{i: n(i)=l} contrib[i, j]
-        delta = A[:, None] + B                                    # (m_j, k)
-        delta[medoids, :] = BIG  # cannot swap a medoid in
+        delta, _ = swap_deltas_numpy(D, medoids)
         j, l = np.unravel_index(np.argmin(delta), delta.shape)
         if delta[j, l] >= -1e-12:
             break
@@ -111,6 +143,35 @@ def kmedoids_numpy(D: np.ndarray, k: int, max_sweeps: int = 100
 # ---------------------------------------------------------------------------
 # JAX on-device solver (natively batched; masked lanes)
 # ---------------------------------------------------------------------------
+
+def _argmin_tied(cost: jnp.ndarray) -> jnp.ndarray:
+    """(C, N) -> (C,) lowest index within TIE_RTOL·|min| of each row's
+    minimum (BUILD picks: costs are objective-sized and positive)."""
+    best = jnp.min(cost, axis=1, keepdims=True)
+    near = cost <= best + TIE_RTOL * jnp.abs(best)
+    return jnp.argmax(near, axis=1).astype(jnp.int32)
+
+
+def _pick_swap(delta: jnp.ndarray, medoids: jnp.ndarray, objective):
+    """One FasterPAM step from the masked (C, M, k) Δ table.
+
+    Takes the lowest flat (j, l) among the improving swaps within
+    TIE_RTOL·objective of the best one; returns (medoids after the swap,
+    and per lane whether it swapped: best Δ < −SWAP_RTOL·objective)."""
+    c, m, k = delta.shape
+    flat_d = delta.reshape(c, m * k)
+    best = jnp.min(flat_d, axis=1)
+    eps = SWAP_RTOL * objective
+    improves = best < -eps
+    thr = jnp.where(improves,
+                    jnp.minimum(best + TIE_RTOL * objective, -eps), best)
+    flat = jnp.argmax(flat_d <= thr[:, None], axis=1)
+    j = (flat // k).astype(jnp.int32)
+    l = (flat % k).astype(jnp.int32)
+    swapped = jnp.where(jnp.arange(k, dtype=jnp.int32)[None]
+                        == l[:, None], j[:, None], medoids)
+    return jnp.where(improves[:, None], swapped, medoids), improves
+
 
 def _take_col(D: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """D (C, M, M), idx (C,) -> (C, M) = D[c, :, idx[c]]."""
@@ -138,13 +199,13 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
 
     cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
                                                       jnp.float32)))
-    first = jnp.argmin(cost0, axis=1).astype(jnp.int32)            # (C,)
+    first = _argmin_tied(cost0)                                    # (C,)
     d_near0 = _take_col(D, first)
 
     def build_step(carry, _):
         d_near, chosen = carry
         cost = jnp.where(chosen | invalid, BIG, add_cost(d_near))
-        nxt = jnp.argmin(cost, axis=1).astype(jnp.int32)
+        nxt = _argmin_tied(cost)
         d_near = jnp.minimum(d_near, _take_col(D, nxt))
         chosen = chosen | (iota_m[None] == nxt[:, None])
         return (d_near, chosen), nxt
@@ -177,7 +238,8 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
             contrib = ((jnp.minimum(D, d2[..., None]) - d1[..., None])
                        * vf[..., None] - shift)
             onehot = jax.nn.one_hot(n_idx, k, dtype=contrib.dtype)
-            B = jnp.einsum("cij,cil->cjl", contrib, onehot)
+            B = jnp.einsum("cij,cil->cjl", contrib, onehot,
+                           precision=F32)
         else:
             d1 = jnp.min(dm, axis=-1)
             n_idx = jnp.argmin(dm, axis=-1).astype(jnp.int32)
@@ -192,22 +254,15 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
         delta = A[..., None] + B                                  # (C, M, k)
         is_medoid = (iota_m[None, :, None] == medoids[:, None, :]).any(-1)
         delta = jnp.where((is_medoid | invalid)[..., None], BIG, delta)
-        flat = jnp.argmin(delta.reshape(c, m * k), axis=1)
-        best = jnp.take_along_axis(delta.reshape(c, m * k), flat[:, None],
-                                   axis=1)[:, 0]
-        j = (flat // k).astype(jnp.int32)
-        l = (flat % k).astype(jnp.int32)
-        swapped = jnp.where(jnp.arange(k, dtype=jnp.int32)[None]
-                            == l[:, None], j[:, None], medoids)
-        medoids = jnp.where((best < -1e-6)[:, None], swapped, medoids)
-        return medoids, best, it + 1
+        medoids, improves = _pick_swap(delta, medoids,
+                                       jnp.sum(d1 * vf, axis=1))
+        return medoids, improves, it + 1
 
     def cond(state):
-        _, best, it = state
-        return jnp.any(best < -1e-6) & (it < max_sweeps)
+        _, improves, it = state
+        return jnp.any(improves) & (it < max_sweeps)
 
-    state = (medoids0.astype(jnp.int32),
-             jnp.full((c,), -jnp.inf, jnp.float32),
+    state = (medoids0.astype(jnp.int32), jnp.ones((c,), bool),
              jnp.asarray(0, jnp.int32))
     medoids, _, _ = jax.lax.while_loop(cond, sweep, state)
 
@@ -236,9 +291,9 @@ def kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
 
     Returns a ``KMedoidsResult`` of stacked fields.  The batched
     ``while_loop`` runs until every lane's swap phase converges; converged
-    lanes are fixed points of the sweep (no Δ < −1e−6 remains, so the
-    masked update is the identity), hence each lane's result equals its
-    standalone ``kmedoids_masked`` solve.
+    lanes are fixed points of the sweep (no Δ < −SWAP_RTOL·objective
+    remains, so the masked update is the identity), hence each lane's
+    result equals its standalone ``kmedoids_masked`` solve.
 
     ``use_kernel`` is the tri-state Pallas switch (None = auto: kernels on
     TPU, jnp elsewhere — see ``repro.kernels.ops.resolve_use_kernel``);
@@ -276,7 +331,7 @@ def _medoid_dists(xf: jnp.ndarray, sq: jnp.ndarray,
     xm = jnp.take_along_axis(xf, medoids[:, :, None], axis=1)  # (C, k, F)
     sqm = jnp.take_along_axis(sq, medoids, axis=1)             # (C, k)
     d2 = (sq[..., None] + sqm[:, None, :]
-          - 2.0 * jnp.einsum("cmf,ckf->cmk", xf, xm))
+          - 2.0 * jnp.einsum("cmf,ckf->cmk", xf, xm, precision=F32))
     d = jnp.sqrt(jnp.maximum(d2, 0.0))
     self_mask = (jnp.arange(m, dtype=jnp.int32)[None, :, None]
                  == medoids[:, None, :])
@@ -306,13 +361,13 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
 
     cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
                                                       jnp.float32)))
-    first = jnp.argmin(cost0, axis=1).astype(jnp.int32)            # (C,)
+    first = _argmin_tied(cost0)                                    # (C,)
     d_near0 = _col_dists(xf, sq, first)
 
     def build_step(carry, _):
         d_near, chosen = carry
         cost = jnp.where(chosen | invalid, BIG, add_cost(d_near))
-        nxt = jnp.argmin(cost, axis=1).astype(jnp.int32)
+        nxt = _argmin_tied(cost)
         d_near = jnp.minimum(d_near, _col_dists(xf, sq, nxt))
         chosen = chosen | (iota_m[None] == nxt[:, None])
         return (d_near, chosen), nxt
@@ -341,22 +396,15 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
         delta = A[..., None] + B                                  # (C, M, k)
         is_medoid = (iota_m[None, :, None] == medoids[:, None, :]).any(-1)
         delta = jnp.where((is_medoid | invalid)[..., None], BIG, delta)
-        flat = jnp.argmin(delta.reshape(c, m * k), axis=1)
-        best = jnp.take_along_axis(delta.reshape(c, m * k), flat[:, None],
-                                   axis=1)[:, 0]
-        j = (flat // k).astype(jnp.int32)
-        l = (flat % k).astype(jnp.int32)
-        swapped = jnp.where(jnp.arange(k, dtype=jnp.int32)[None]
-                            == l[:, None], j[:, None], medoids)
-        medoids = jnp.where((best < -1e-6)[:, None], swapped, medoids)
-        return medoids, best, it + 1
+        medoids, improves = _pick_swap(delta, medoids,
+                                       jnp.sum(d1 * vf, axis=1))
+        return medoids, improves, it + 1
 
     def cond(state):
-        _, best, it = state
-        return jnp.any(best < -1e-6) & (it < max_sweeps)
+        _, improves, it = state
+        return jnp.any(improves) & (it < max_sweeps)
 
-    state = (medoids0.astype(jnp.int32),
-             jnp.full((c,), -jnp.inf, jnp.float32),
+    state = (medoids0.astype(jnp.int32), jnp.ones((c,), bool),
              jnp.asarray(0, jnp.int32))
     medoids, _, _ = jax.lax.while_loop(cond, sweep, state)
 
@@ -432,5 +480,6 @@ def pairwise_sq_dists(x: jnp.ndarray,
     if resolve_use_kernel(use_kernel):
         return pairwise_l2(x, squared=True, zero_diag=True)
     sq = jnp.sum(jnp.square(x), axis=-1)
-    d = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * (x @ x.T), 0.0)
+    d = jnp.maximum(sq[:, None] + sq[None, :]
+                    - 2.0 * jnp.matmul(x, x.T, precision=F32), 0.0)
     return zero_self_diag(d)

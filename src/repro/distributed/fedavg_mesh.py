@@ -18,7 +18,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -64,7 +63,7 @@ def fedavg_allreduce(local_params, weights, mesh: Mesh,
     in_specs = (P(axes),) + tuple(
         P(*((axes,) + (None,) * (leaf.ndim - 1))) for leaf in flat)
     out_specs = tuple(P(*((None,) * (leaf.ndim - 1))) for leaf in flat)
-    fn = shard_map(agg, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(agg, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(weights, *flat)
     return jax.tree.unflatten(treedef, list(out))

@@ -190,13 +190,11 @@ def example_batch(workload, batch_size: int = 8) -> Dict[str, Any]:
 
 
 def _compiled_flops(compiled) -> Optional[float]:
-    """``compiled.cost_analysis()`` across jax versions (dict vs [dict])."""
+    """HLO FLOPs from ``compiled.cost_analysis()`` (None if unreported)."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     if not cost:
         return None
     flops = float(cost.get("flops", -1.0))

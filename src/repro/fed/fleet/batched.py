@@ -500,24 +500,22 @@ class FleetEngine:
         return jax.tree.map(
             lambda x: jnp.broadcast_to(x[None], (c,) + x.shape), params)
 
-    def _batch_indices(self, group: CohortGroup, epochs: slice, sl: slice
+    def _batch_indices(self, group: CohortGroup, epochs: slice, lanes
                        ) -> jnp.ndarray:
-        """(C, T, B) minibatch index tensor for the given epoch/client
-        ranges (sliced host-side so the loop path pays per-client, not
-        per-group, conversion cost)."""
-        sel = group.perms[sl, epochs]                      # (C, e, M)
+        """(C, T, B) minibatch index tensor of the program lanes
+        (``_lanes``) for the given epoch range."""
+        sel = group.perms[lanes, epochs]                   # (C, e, M)
         c, e, m_pad = sel.shape
         b = self.cfg.batch_size
         return jnp.asarray(sel.reshape(c, e * (m_pad // b), b))
 
     # -- group execution --------------------------------------------------
 
-    def _run_group_stacked(self, params: Pytree, group: CohortGroup,
-                           sl: slice) -> Tuple[Pytree, jnp.ndarray,
-                                               Optional[jnp.ndarray]]:
-        """Run clients ``sl`` of a group as ONE jitted dispatch; returns
-        (params (C,...), losses, medoid indices or None)."""
-        cfg = self.cfg
+    def _run_group_stacked(self, params: Pytree, group: CohortGroup
+                           ) -> Tuple[Pytree, jnp.ndarray,
+                                      Optional[jnp.ndarray]]:
+        """Run a group as ONE jitted dispatch; returns (params (C,...),
+        losses, medoid indices or None)."""
         # asarray never changes the treedef, so the program cache can be
         # consulted before staging — letting the dispatch span charge the
         # host-side transfers to the phase they belong to
@@ -525,37 +523,56 @@ class FleetEngine:
                                       jax.tree.structure(group.data))
         self.count_dispatch()
         name = "local_sgd" if group.k == 0 else "coreset_group"
-
-        if group.k == 0:    # full-set: E epochs of minibatch SGD
-            with self._dispatch_span(name, program, k=0,
-                                     n_clients=group.n_clients):
-                # host-side slice, then one device transfer per call: the
-                # batched path ships the whole group at once, the loop
-                # path one client at a time
-                data = jax.tree.map(lambda v: jnp.asarray(v[sl]),
-                                    group.data)
-                c = int(jax.tree.leaves(data)[0].shape[0])
-                w = jnp.asarray(group.valid[sl].astype(np.float32))
-                p0 = self._broadcast_params(params, c)
-                idx = self._batch_indices(group, slice(None), sl)
-                p, losses, _ = program(params, p0, data, w, idx)
-            return p, losses, None
-
-        # Alg. 1 straggler path: features at round-start params, fused
-        # coreset selection, one full-set epoch, E−1 coreset epochs —
-        # all inside the one program.
-        with self._dispatch_span(name, program, k=group.k,
-                                 n_clients=group.n_clients):
-            data = jax.tree.map(lambda v: jnp.asarray(v[sl]), group.data)
-            c = int(jax.tree.leaves(data)[0].shape[0])
-            w = jnp.asarray(group.valid[sl].astype(np.float32))  # (C, M)
-            p0 = self._broadcast_params(params, c)
-            idx1 = self._batch_indices(group, slice(0, 1), sl)
-            valid = jnp.asarray(group.valid[sl])
-            steps = jnp.zeros((c, max(cfg.epochs - 1, 1)))
-            p, losses, meds = program(params, p0, data, w, valid, idx1,
-                                      steps)
+        c = group.n_clients
+        with self._dispatch_span(name, program, k=group.k, n_clients=c):
+            p, losses, meds = program(*self._group_args(params, group))
+            if losses.shape[0] != c:        # a lone client's second lane
+                p = jax.tree.map(lambda x: x[:c], p)
+                losses = losses[:c]
+                meds = None if meds is None else meds[:c]
         return p, losses, meds
+
+    @staticmethod
+    def _lanes(group: CohortGroup):
+        """Index of the group rows that become program lanes: all of them
+        (a view), or a lone coreset client repeated to two.  With one
+        lane, vmap leaves the plain SmallCNN gradient over the k-sample
+        coreset batch, and the TPU compiler's code generation for it grows
+        faster than k: 17 s at k = 256, 82 s at k = 512 for that gradient
+        alone, over 700 s for the whole k = 1024 group program (compiled
+        for a described v5e).  With two lanes the lane axis stays in the
+        convolutions, which compile in seconds.  The extra lane is sliced
+        off the outputs."""
+        return [0, 0] if group.n_clients == 1 and group.k else slice(None)
+
+    def _group_args(self, params: Pytree, group: CohortGroup) -> Tuple:
+        """Device arguments of the group program, one device transfer per
+        array.  ``k == 0``: E epochs of minibatch SGD.  ``k > 0``: the
+        Alg. 1 straggler path — features at round-start params, fused
+        coreset selection, one full-set epoch, E−1 coreset epochs — all
+        inside the one program."""
+        lanes = self._lanes(group)
+        data = jax.tree.map(lambda v: jnp.asarray(v[lanes]), group.data)
+        valid = group.valid[lanes]                              # (C, M)
+        c = valid.shape[0]
+        w = jnp.asarray(valid.astype(np.float32))
+        p0 = self._broadcast_params(params, c)
+        if group.k == 0:
+            idx = self._batch_indices(group, slice(None), lanes)
+            return params, p0, data, w, idx
+        idx1 = self._batch_indices(group, slice(0, 1), lanes)
+        steps = jnp.zeros((c, max(self.cfg.epochs - 1, 1)))
+        return params, p0, data, w, jnp.asarray(valid), idx1, steps
+
+    def stage_group(self, params: Pytree, group: CohortGroup
+                    ) -> Tuple[Any, Tuple]:
+        """(jitted program, device arguments) that ``run_group`` would
+        dispatch for ``group`` — for checks that read the compiled
+        program (``program.lower(*args).compile().as_text()``) or the
+        arguments' placement."""
+        program = self._group_program(group.k,
+                                      jax.tree.structure(group.data))
+        return program, self._group_args(params, group)
 
     def _run_client_loop(self, params: Pytree, group: CohortGroup, c: int
                          ) -> Tuple[Pytree, float, Optional[np.ndarray]]:
@@ -605,10 +622,10 @@ class FleetEngine:
                   batched: bool = True) -> Tuple[Pytree, np.ndarray,
                                                  Optional[np.ndarray]]:
         if batched:
-            p, losses, meds = self._run_group_stacked(
-                params, group, slice(None))
-            return (p, np.asarray(losses),
-                    None if meds is None else np.asarray(meds))
+            # losses and medoids stay on the device: converting here would
+            # wait for this group before the next one is dispatched (the
+            # round driver converts in its gather span)
+            return self._run_group_stacked(params, group)
         # the per-client Python loop the batched engine replaces
         ps, losses, meds = [], [], []
         name = "local_sgd" if group.k == 0 else "coreset_group"

@@ -18,14 +18,20 @@ Execution contract (what makes sharding a pure performance choice):
   * the per-client arithmetic is literally the batched engine's —
     ``ShardedFleetEngine`` re-vmaps the raw ``sgd_scan`` / ``core_scan``
     programs a ``FleetEngine`` builds, so each client lane computes the
-    same op sequence regardless of which device it lands on.  Medoid
-    choices are bit-identical to the batched engine; aggregated params
-    agree to float32 summation-order tolerance (local partial sums +
-    psum vs one host tensordot);
-  * groups are padded host-side to a multiple of the device count by
-    repeating the last client lane with **zero aggregation weight**, so
-    padding can never perturb the weighted mean, and padded medoid /
-    loss lanes are sliced off before returning;
+    same op sequence regardless of which device it lands on.  On the
+    CPU, medoid choices are bit-identical to the batched engine and
+    aggregated params agree to float32 summation-order tolerance (local
+    partial sums + psum vs one host tensordot).  On a TPU they are not
+    yet: a group program's selection can depend on how many lanes it
+    holds (on one v5e, the same program on 13 lanes and on 4-lane
+    chunks picked different medoids in 1 lane of 13), and the
+    per-device program holds fewer lanes than the one-device program
+    (ROADMAP, "sharded parity on TPU");
+  * groups are padded host-side to a multiple of the device count (at
+    least two lanes per device in a coreset group) by repeating the last
+    client lane with **zero aggregation weight**, so padding can never
+    perturb the weighted mean, and padded medoid / loss lanes are sliced
+    off before returning;
   * inputs are placed with ``NamedSharding`` over the ``"clients"`` mesh
     axis (the same placement machinery as ``repro.distributed``), and
     the weighted reduction reuses ``weighted_psum_sum`` from
@@ -45,7 +51,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.fedavg_mesh import weighted_psum_sum
@@ -150,8 +155,8 @@ class ShardedFleetEngine(FleetEngine):
         @jax.jit
         def program(params, *args):
             in_specs, out_specs = specs(params)
-            fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+            fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             return fn(params, *args)
 
         return program
@@ -173,9 +178,7 @@ class ShardedFleetEngine(FleetEngine):
         only gathered when a robust aggregation rule or fault-corruption
         pass actually consumes it (the weighted-mean path uses the
         psum-reduced sum and never touches it)."""
-        cfg = self.cfg
         c = group.n_clients
-        pad = (-c) % self.n_devices
         # shard_put never changes the treedef, so the cache is consulted
         # before staging and the dispatch span covers the host-side
         # padding + device placement along with the program call
@@ -191,34 +194,56 @@ class ShardedFleetEngine(FleetEngine):
         # group has been enqueued
         with self._dispatch_span(name, program, k=group.k, n_clients=c,
                                  sharded=True):
-            lane_w = np.concatenate(
-                [np.asarray(weights, np.float32),
-                 np.zeros(pad, np.float32)])
-            data = jax.tree.map(
-                lambda v: self._shard_put(_pad_lanes(np.asarray(v), pad)),
-                group.data)
-            w = self._shard_put(
-                _pad_lanes(group.valid.astype(np.float32), pad))
-            lane_w = self._shard_put(lane_w)
-            m_pad = group.valid.shape[1]
-            t_full = cfg.epochs * (m_pad // cfg.batch_size)
-            idx_all = group.perms.reshape(c, t_full, cfg.batch_size)
+            args = self._sharded_args(params, group, weights)
             if group.k == 0:
-                idx = self._shard_put(_pad_lanes(idx_all, pad))
-                part, wsum, losses, stack = program(params, data, w,
-                                                    lane_w, idx)
+                part, wsum, losses, stack = program(*args)
                 stack = jax.tree.map(lambda x: x[:c], stack)
                 return part, wsum, losses[:c], None, stack
-            idx1 = self._shard_put(
-                _pad_lanes(idx_all[:, : m_pad // cfg.batch_size], pad))
-            valid = self._shard_put(_pad_lanes(group.valid, pad))
-            steps = self._shard_put(
-                np.zeros((c + pad, max(cfg.epochs - 1, 1)), np.float32))
-            part, wsum, losses, meds, stack = program(params, data, w,
-                                                      lane_w, idx1, valid,
-                                                      steps)
+            part, wsum, losses, meds, stack = program(*args)
             stack = jax.tree.map(lambda x: x[:c], stack)
         return part, wsum, losses[:c], meds[:c], stack
+
+    def _sharded_args(self, params: Pytree, group: CohortGroup,
+                      weights: np.ndarray) -> Tuple:
+        """Mesh-placed arguments of the group's shard_mapped program:
+        lanes padded (zero aggregation weight) to a device multiple, at
+        least two per device in a coreset group, each per-client array
+        split over the ``"clients"`` axis."""
+        cfg = self.cfg
+        c = group.n_clients
+        n = self.n_devices
+        lanes = -(-c // n) * n
+        if group.k:     # two lanes per device: see FleetEngine._lanes
+            lanes = max(lanes, 2 * n)
+        pad = lanes - c
+        lane_w = np.concatenate([np.asarray(weights, np.float32),
+                                 np.zeros(pad, np.float32)])
+        data = jax.tree.map(
+            lambda v: self._shard_put(_pad_lanes(np.asarray(v), pad)),
+            group.data)
+        w = self._shard_put(_pad_lanes(group.valid.astype(np.float32), pad))
+        lane_w = self._shard_put(lane_w)
+        m_pad = group.valid.shape[1]
+        t_full = cfg.epochs * (m_pad // cfg.batch_size)
+        idx_all = group.perms.reshape(c, t_full, cfg.batch_size)
+        if group.k == 0:
+            idx = self._shard_put(_pad_lanes(idx_all, pad))
+            return params, data, w, lane_w, idx
+        idx1 = self._shard_put(
+            _pad_lanes(idx_all[:, : m_pad // cfg.batch_size], pad))
+        valid = self._shard_put(_pad_lanes(group.valid, pad))
+        steps = self._shard_put(
+            np.zeros((c + pad, max(cfg.epochs - 1, 1)), np.float32))
+        return params, data, w, lane_w, idx1, valid, steps
+
+    def stage_group(self, params: Pytree, group: CohortGroup
+                    ) -> Tuple[Any, Tuple]:
+        """(shard_mapped program, mesh-placed arguments) that
+        ``run_group_sharded`` would dispatch for ``group`` (unit
+        aggregation weights)."""
+        program = self._program(group.k, jax.tree.structure(group.data))
+        return program, self._sharded_args(
+            params, group, np.ones(group.n_clients, np.float32))
 
     def combine_group_sums(self, partials: List[Tuple[Pytree, jnp.ndarray]],
                            fallback: Pytree) -> Pytree:

@@ -53,6 +53,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import F32   # Mosaic's fp32 contract precision
+
+
+# Per-point (C, M) vectors enter the kernels 3-D so that every block's
+# last two dims satisfy Mosaic's (8, 128) tiling rule: a (1, block_m)
+# block of a (C, M) array does not.  Vectors indexed by the tile's rows
+# (i) ride as (C, M, 1) and read as (bi, 1) columns; vectors indexed by
+# its columns (j), and the per-candidate outputs, ride as (C, 1, M) and
+# read as (1, bj) rows.
+
+def _col(v: jnp.ndarray) -> jnp.ndarray:
+    return v[:, :, None]
+
+
+def _row(v: jnp.ndarray) -> jnp.ndarray:
+    return v[:, None, :]
+
 
 def _build_cost_kernel(d_ref, dn_ref, vf_ref, out_ref, acc_ref, *, n_i: int):
     i_step = pl.program_id(2)
@@ -62,14 +79,14 @@ def _build_cost_kernel(d_ref, dn_ref, vf_ref, out_ref, acc_ref, *, n_i: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     d = d_ref[0].astype(jnp.float32)             # (bi, bj) distance tile
-    dn = dn_ref[0].astype(jnp.float32)           # (bi,) current d_near
-    vf = vf_ref[0].astype(jnp.float32)           # (bi,) valid mask
-    add = jnp.minimum(dn[:, None], d) * vf[:, None]
+    dn = dn_ref[0].astype(jnp.float32)           # (bi, 1) current d_near
+    vf = vf_ref[0].astype(jnp.float32)           # (bi, 1) valid mask
+    add = jnp.minimum(dn, d) * vf
     acc_ref[...] += jnp.sum(add, axis=0, keepdims=True)   # (1, bj)
 
     @pl.when(i_step == n_i - 1)
     def _epilogue():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
 def build_cost_pallas(D: jnp.ndarray, d_near: jnp.ndarray, vf: jnp.ndarray,
@@ -94,14 +111,14 @@ def build_cost_pallas(D: jnp.ndarray, d_near: jnp.ndarray, vf: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_m, block_m), lambda b, j, i: (b, i, j)),
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i: (b, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_m), lambda b, j, i: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((c, m), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_m), lambda b, j, i: (b, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((c, 1, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_m), jnp.float32)],
         interpret=interpret,
-    )(D, d_near, vf)
+    )(D, _col(d_near), _col(vf))[:, 0]
 
 
 def _delta_sweep_kernel(d_ref, d1_ref, d2_ref, vf_ref, oh_ref, a_ref, b_ref,
@@ -114,9 +131,9 @@ def _delta_sweep_kernel(d_ref, d1_ref, d2_ref, vf_ref, oh_ref, a_ref, b_ref,
         acc_b_ref[...] = jnp.zeros_like(acc_b_ref)
 
     d = d_ref[0].astype(jnp.float32)             # (bi, bj)
-    d1 = d1_ref[0].astype(jnp.float32)[:, None]  # (bi, 1) nearest-medoid dist
-    d2 = d2_ref[0].astype(jnp.float32)[:, None]  # (bi, 1) second-nearest
-    vf = vf_ref[0].astype(jnp.float32)[:, None]  # (bi, 1) valid mask
+    d1 = d1_ref[0].astype(jnp.float32)           # (bi, 1) nearest-medoid dist
+    d2 = d2_ref[0].astype(jnp.float32)           # (bi, 1) second-nearest
+    vf = vf_ref[0].astype(jnp.float32)           # (bi, 1) valid mask
     oh = oh_ref[0].astype(jnp.float32)           # (bi, K) one_hot(n_idx)
 
     # one read of the tile feeds both reductions
@@ -124,12 +141,12 @@ def _delta_sweep_kernel(d_ref, d1_ref, d2_ref, vf_ref, oh_ref, a_ref, b_ref,
     contrib = (jnp.clip(d, d1, d2) - d1) * vf              # per-cluster term
     acc_a_ref[...] += jnp.sum(shift, axis=0, keepdims=True)        # (1, bj)
     acc_b_ref[...] += jax.lax.dot_general(                 # contribᵀ @ onehot
-        contrib, oh, (((0,), (0,)), ((), ())),
+        contrib, oh, (((0,), (0,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)                # (bj, K)
 
     @pl.when(i_step == n_i - 1)
     def _epilogue():
-        a_ref[...] = acc_a_ref[...].astype(a_ref.dtype)
+        a_ref[0] = acc_a_ref[...].astype(a_ref.dtype)
         b_ref[0] = acc_b_ref[...].astype(b_ref.dtype)
 
 
@@ -153,28 +170,29 @@ def delta_sweep_pallas(D: jnp.ndarray, d1: jnp.ndarray, d2: jnp.ndarray,
 
     grid = (c, n_i, n_i)                          # (client, j-tile, i-step)
     kernel = functools.partial(_delta_sweep_kernel, n_i=n_i)
-    return pl.pallas_call(
+    A, B = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_m, block_m), lambda b, j, i: (b, i, j)),
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, block_m, kp), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_m), lambda b, j, i: (b, j)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i: (b, 0, j)),
             pl.BlockSpec((1, block_m, kp), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c, m), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1, m), jnp.float32),
             jax.ShapeDtypeStruct((c, m, kp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_m), jnp.float32),
                         pltpu.VMEM((block_m, kp), jnp.float32)],
         interpret=interpret,
-    )(D, d1, d2, vf, n_onehot)
+    )(D, _col(d1), _col(d2), _col(vf), n_onehot)
+    return A[:, 0], B
 
 
 # ---------------------------------------------------------------------------
@@ -191,7 +209,7 @@ def _dist_tile(dot, sqi, sqj, i_step, j_step, block_m):
     diagonal (global row index == global col index) pinned to exact 0 —
     the float32 cancellation fix-up ``ops.zero_self_diag`` applies to
     materialized stacks, fused into the tile here."""
-    d = jnp.sqrt(jnp.maximum(sqi[:, None] + sqj[None, :] - 2.0 * dot, 0.0))
+    d = jnp.sqrt(jnp.maximum(sqi + sqj - 2.0 * dot, 0.0))
     rows = i_step * block_m + jax.lax.broadcasted_iota(
         jnp.int32, (block_m, block_m), 0)
     cols = j_step * block_m + jax.lax.broadcasted_iota(
@@ -217,7 +235,7 @@ def _build_cost_feats_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, dn_ref,
     xi = xi_ref[0].astype(jnp.float32)           # (bi, bk) feature rows
     xj = xj_ref[0].astype(jnp.float32)           # (bj, bk) candidate rows
     dot_ref[...] += jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())),
+        xi, xj, (((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)      # xi @ xj.T
 
     @pl.when(k_step == n_k - 1)
@@ -225,16 +243,16 @@ def _build_cost_feats_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, dn_ref,
         d = _dist_tile(dot_ref[...], sqi_ref[0].astype(jnp.float32),
                        sqj_ref[0].astype(jnp.float32), i_step, j_step,
                        block_m)
-        dn = dn_ref[0].astype(jnp.float32)       # (bi,) current d_near
-        vf = vfi_ref[0].astype(jnp.float32)      # (bi,) valid rows
-        add = jnp.minimum(dn[:, None], d) * vf[:, None]
+        dn = dn_ref[0].astype(jnp.float32)       # (bi, 1) current d_near
+        vf = vfi_ref[0].astype(jnp.float32)      # (bi, 1) valid rows
+        add = jnp.minimum(dn, d) * vf
         acc_ref[...] += jnp.sum(add, axis=0, keepdims=True)   # (1, bj)
 
     @pl.when((i_step == n_i - 1) & (k_step == n_k - 1))
     def _epilogue():
-        vfj = vfj_ref[0].astype(jnp.float32)     # (bj,) valid candidates
-        cost = jnp.where(vfj[None, :] > 0.0, acc_ref[...], BIG)
-        out_ref[...] = cost.astype(out_ref.dtype)
+        vfj = vfj_ref[0].astype(jnp.float32)     # (1, bj) valid candidates
+        cost = jnp.where(vfj > 0.0, acc_ref[...], BIG)
+        out_ref[0] = cost.astype(out_ref.dtype)
 
 
 def build_cost_from_feats_pallas(x: jnp.ndarray, d_near: jnp.ndarray,
@@ -266,18 +284,18 @@ def build_cost_from_feats_pallas(x: jnp.ndarray, d_near: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, block_m, block_k), lambda b, j, i, k: (b, i, k)),
             pl.BlockSpec((1, block_m, block_k), lambda b, j, i, k: (b, j, k)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((c, m), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((c, 1, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32),
                         pltpu.VMEM((1, block_m), jnp.float32)],
         interpret=interpret,
-    )(x, x, sq, sq, d_near, vf, vf)
+    )(x, x, _col(sq), _row(sq), _col(d_near), _col(vf), _row(vf))[:, 0]
 
 
 def _delta_sweep_feats_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, d1_ref,
@@ -300,7 +318,7 @@ def _delta_sweep_feats_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, d1_ref,
     xi = xi_ref[0].astype(jnp.float32)           # (bi, bk)
     xj = xj_ref[0].astype(jnp.float32)           # (bj, bk)
     dot_ref[...] += jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())),
+        xi, xj, (((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
     @pl.when(k_step == n_k - 1)
@@ -308,22 +326,22 @@ def _delta_sweep_feats_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, d1_ref,
         d = _dist_tile(dot_ref[...], sqi_ref[0].astype(jnp.float32),
                        sqj_ref[0].astype(jnp.float32), i_step, j_step,
                        block_m)
-        d1 = d1_ref[0].astype(jnp.float32)[:, None]   # (bi, 1)
-        d2 = d2_ref[0].astype(jnp.float32)[:, None]
-        vf = vfi_ref[0].astype(jnp.float32)[:, None]
+        d1 = d1_ref[0].astype(jnp.float32)            # (bi, 1)
+        d2 = d2_ref[0].astype(jnp.float32)
+        vf = vfi_ref[0].astype(jnp.float32)
         oh = oh_ref[0].astype(jnp.float32)            # (bi, K)
         shift = (jnp.minimum(d, d1) - d1) * vf        # ≤ 0 removal gain
         contrib = (jnp.clip(d, d1, d2) - d1) * vf     # per-cluster term
         acc_a_ref[...] += jnp.sum(shift, axis=0, keepdims=True)   # (1, bj)
         acc_b_ref[...] += jax.lax.dot_general(        # contribᵀ @ onehot
-            contrib, oh, (((0,), (0,)), ((), ())),
+            contrib, oh, (((0,), (0,)), ((), ())), precision=F32,
             preferred_element_type=jnp.float32)       # (bj, K)
 
     @pl.when((i_step == n_i - 1) & (k_step == n_k - 1))
     def _epilogue():
-        vfj = vfj_ref[0].astype(jnp.float32)          # (bj,)
-        a = jnp.where(vfj[None, :] > 0.0, acc_a_ref[...], BIG)
-        a_ref[...] = a.astype(a_ref.dtype)
+        vfj = vfj_ref[0].astype(jnp.float32)          # (1, bj)
+        a = jnp.where(vfj > 0.0, acc_a_ref[...], BIG)
+        a_ref[0] = a.astype(a_ref.dtype)
         b_ref[0] = acc_b_ref[...].astype(b_ref.dtype)
 
 
@@ -354,30 +372,32 @@ def delta_sweep_from_feats_pallas(x: jnp.ndarray, d1: jnp.ndarray,
     grid = (c, n_i, n_i, n_k)            # (client, j-tile, i-step, F-step)
     kernel = functools.partial(_delta_sweep_feats_kernel, n_i=n_i, n_k=n_k,
                                block_m=block_m)
-    return pl.pallas_call(
+    A, B = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_m, block_k), lambda b, j, i, k: (b, i, k)),
             pl.BlockSpec((1, block_m, block_k), lambda b, j, i, k: (b, j, k)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, block_m, 1), lambda b, j, i, k: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
             pl.BlockSpec((1, block_m, kp), lambda b, j, i, k: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_m), lambda b, j, i, k: (b, j)),
+            pl.BlockSpec((1, 1, block_m), lambda b, j, i, k: (b, 0, j)),
             pl.BlockSpec((1, block_m, kp), lambda b, j, i, k: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c, m), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1, m), jnp.float32),
             jax.ShapeDtypeStruct((c, m, kp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32),
                         pltpu.VMEM((1, block_m), jnp.float32),
                         pltpu.VMEM((block_m, kp), jnp.float32)],
         interpret=interpret,
-    )(x, x, sq, sq, d1, d2, vf, vf, n_onehot)
+    )(x, x, _col(sq), _row(sq), _col(d1), _col(d2), _col(vf), _row(vf),
+      n_onehot)
+    return A[:, 0], B

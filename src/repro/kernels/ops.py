@@ -261,7 +261,7 @@ def _feats_dist_chunk(xf, sq, j0, chunk):
     xj = jax.lax.dynamic_slice_in_dim(xf, j0, chunk, axis=1)
     sqj = jax.lax.dynamic_slice_in_dim(sq, j0, chunk, axis=1)
     d2 = (sq[..., :, None] + sqj[..., None, :]
-          - 2.0 * jnp.einsum("cif,cjf->cij", xf, xj))
+          - 2.0 * jnp.einsum("cif,cjf->cij", xf, xj, precision=ref.F32))
     d = jnp.sqrt(jnp.maximum(d2, 0.0))
     rows = jnp.arange(xf.shape[1])[None, :, None]
     cols = (j0 + jnp.arange(chunk))[None, None, :]
@@ -312,7 +312,7 @@ def _delta_sweep_from_feats_jnp(x, d1, d2, vf, n_onehot, *, chunk: int):
         shift = (jnp.minimum(d, d1e) - d1e) * vfe
         contrib = (jnp.clip(d, d1e, d2e) - d1e) * vfe
         a = jnp.sum(shift, axis=-2)               # (C, chunk)
-        b = jnp.einsum("cij,cil->cjl", contrib, ohp)
+        b = jnp.einsum("cij,cil->cjl", contrib, ohp, precision=ref.F32)
         vfj = jax.lax.dynamic_slice_in_dim(vfp, j0, chunk, axis=1)
         return jnp.where(vfj > 0.0, a, _BIG), b
 

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import F32   # Mosaic's fp32 contract precision
+
 
 def _pairwise_kernel(x_ref, y_ref, xsq_ref, ysq_ref, out_ref, acc_ref, *,
                      squared: bool, n_k: int):
@@ -36,14 +38,14 @@ def _pairwise_kernel(x_ref, y_ref, xsq_ref, ysq_ref, out_ref, acc_ref, *,
     x = x_ref[...].astype(jnp.float32)           # (bm, bk)
     y = y_ref[...].astype(jnp.float32)           # (bn, bk)
     acc_ref[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
+        x, y, (((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)      # x @ y.T
 
     @pl.when(k_step == n_k - 1)
     def _epilogue():
-        xsq = xsq_ref[...].astype(jnp.float32)   # (bm,)
-        ysq = ysq_ref[...].astype(jnp.float32)   # (bn,)
-        d = xsq[:, None] + ysq[None, :] - 2.0 * acc_ref[...]
+        xsq = xsq_ref[...].astype(jnp.float32)   # (bm, 1)
+        ysq = ysq_ref[...].astype(jnp.float32)   # (1, bn)
+        d = xsq + ysq - 2.0 * acc_ref[...]
         d = jnp.maximum(d, 0.0)
         if not squared:
             d = jnp.sqrt(d)
@@ -67,8 +69,10 @@ def pairwise_l2_pallas(x: jnp.ndarray, y: Optional[jnp.ndarray] = None, *,
     assert m % block_m == 0 and n % block_n == 0 and d % block_k == 0
     n_k = d // block_k
 
-    xsq = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)
-    ysq = jnp.sum(y.astype(jnp.float32) ** 2, axis=-1)
+    # 2-D norm operands: (bm, 1) column / (1, bn) row blocks match
+    # Mosaic's tiled layout, which 1-D (bm,) blocks do not.
+    xsq = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)[:, None]
+    ysq = jnp.sum(y.astype(jnp.float32) ** 2, axis=-1)[None, :]
 
     grid = (m // block_m, n // block_n, n_k)
     kernel = functools.partial(_pairwise_kernel, squared=squared, n_k=n_k)
@@ -78,8 +82,8 @@ def pairwise_l2_pallas(x: jnp.ndarray, y: Optional[jnp.ndarray] = None, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_n, block_k), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_m,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j, k: (j,)),
+            pl.BlockSpec((block_m, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
@@ -99,14 +103,14 @@ def _pairwise_batched_kernel(xi_ref, xj_ref, sqi_ref, sqj_ref, out_ref,
     xi = xi_ref[0].astype(jnp.float32)           # (bm, bk) rows
     xj = xj_ref[0].astype(jnp.float32)           # (bn, bk) cols
     acc_ref[...] += jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())),
+        xi, xj, (((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)      # xi @ xj.T for this client
 
     @pl.when(k_step == n_k - 1)
     def _epilogue():
-        sqi = sqi_ref[0].astype(jnp.float32)     # (bm,)
-        sqj = sqj_ref[0].astype(jnp.float32)     # (bn,)
-        d = sqi[:, None] + sqj[None, :] - 2.0 * acc_ref[...]
+        sqi = sqi_ref[0].astype(jnp.float32)     # (bm, 1)
+        sqj = sqj_ref[0].astype(jnp.float32)     # (1, bn)
+        d = sqi + sqj - 2.0 * acc_ref[...]
         d = jnp.maximum(d, 0.0)
         if not squared:
             d = jnp.sqrt(d)
@@ -131,6 +135,8 @@ def pairwise_l2_batched_pallas(x: jnp.ndarray, *, squared: bool = False,
     n_k = d // block_k
 
     xsq = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)     # (C, M)
+    # (C, M, 1) column / (C, 1, M) row norm operands keep each block's
+    # last two dims Mosaic-tileable (a (1, bm) block of (C, M) is not).
 
     grid = (c, m // block_m, m // block_m, n_k)
     kernel = functools.partial(_pairwise_batched_kernel, squared=squared,
@@ -141,12 +147,12 @@ def pairwise_l2_batched_pallas(x: jnp.ndarray, *, squared: bool = False,
         in_specs=[
             pl.BlockSpec((1, block_m, block_k), lambda b, i, j, k: (b, i, k)),
             pl.BlockSpec((1, block_m, block_k), lambda b, i, j, k: (b, j, k)),
-            pl.BlockSpec((1, block_m), lambda b, i, j, k: (b, i)),
-            pl.BlockSpec((1, block_m), lambda b, i, j, k: (b, j)),
+            pl.BlockSpec((1, block_m, 1), lambda b, i, j, k: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda b, i, j, k: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_m, block_m),
                                lambda b, i, j, k: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((c, m, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32)],
         interpret=interpret,
-    )(x, x, xsq, xsq)
+    )(x, x, xsq[:, :, None], xsq[:, None, :])

@@ -11,6 +11,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+# Distance and Δ-sweep contractions run at full f32 precision: on a TPU an
+# f32 matmul at default precision is one bf16 pass, which would leave the
+# oracles (and the jnp fallbacks built on them) ~1e-3 off the kernels.
+F32 = jax.lax.Precision.HIGHEST
+
 
 def pairwise_l2_ref(x: jnp.ndarray, y: Optional[jnp.ndarray] = None, *,
                     squared: bool = False) -> jnp.ndarray:
@@ -19,7 +24,8 @@ def pairwise_l2_ref(x: jnp.ndarray, y: Optional[jnp.ndarray] = None, *,
     xf = x.astype(jnp.float32)
     yf = y.astype(jnp.float32)
     sq = (jnp.sum(xf * xf, axis=-1)[:, None]
-          + jnp.sum(yf * yf, axis=-1)[None, :] - 2.0 * (xf @ yf.T))
+          + jnp.sum(yf * yf, axis=-1)[None, :]
+          - 2.0 * jnp.matmul(xf, yf.T, precision=F32))
     sq = jnp.maximum(sq, 0.0)
     return sq if squared else jnp.sqrt(sq)
 
@@ -87,7 +93,7 @@ def kmedoids_delta_sweep_ref(D: jnp.ndarray, d1: jnp.ndarray,
     shift = (jnp.minimum(D, d1e) - d1e) * vf[..., None]
     contrib = (jnp.clip(D, d1e, d2[..., None]) - d1e) * vf[..., None]
     A = jnp.sum(shift, axis=-2)
-    B = jnp.einsum("...ij,...il->...jl", contrib, n_onehot)
+    B = jnp.einsum("...ij,...il->...jl", contrib, n_onehot, precision=F32)
     return A, B
 
 
@@ -105,7 +111,7 @@ def _pairwise_from_feats(x: jnp.ndarray) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     sq = jnp.sum(xf * xf, axis=-1)
     d2 = (sq[..., :, None] + sq[..., None, :]
-          - 2.0 * jnp.einsum("...if,...jf->...ij", xf, xf))
+          - 2.0 * jnp.einsum("...if,...jf->...ij", xf, xf, precision=F32))
     d = jnp.sqrt(jnp.maximum(d2, 0.0))
     m = x.shape[-2]
     eye = jnp.eye(m, dtype=bool)

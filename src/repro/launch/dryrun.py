@@ -244,8 +244,6 @@ def dry_run(arch_id: str, shape_name: str, multi_pod: bool = False,
     mem = compiled.memory_analysis()
     record["memory"] = _memory_dict(mem)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per device
-        cost = cost[0] if cost else None
     record["cost"] = {k: v for k, v in cost.items()
                       if k in ("flops", "bytes accessed")} if cost else {}
     try:

@@ -34,6 +34,7 @@ from repro.models.model import Model
 from repro.models.training import make_train_step
 from repro.optim.optimizers import adam, sgd
 from repro.optim.schedules import warmup_cosine_lr
+from repro.utils import xla_env
 from repro.utils.tree import param_count, tree_weighted_mean
 
 PRESETS = {
@@ -208,6 +209,7 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--straggler-pct", type=float, default=30.0)
     args = ap.parse_args(argv)
+    xla_env.enable_compile_cache()
 
     cfg = PRESETS.get(args.preset) or get_config(args.preset, smoke=True)
     if args.fedcore:

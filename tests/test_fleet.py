@@ -182,6 +182,39 @@ def test_batched_engine_matches_per_client_loop(fleet_fl):
     np.testing.assert_allclose(sb.losses, sl.losses, atol=1e-5)
 
 
+def test_lone_coreset_client_runs_as_two_lanes(fleet_fl):
+    """A one-client coreset group is dispatched with its client repeated
+    to two lanes (a one-lane program compiles for minutes on a TPU) and
+    returns one lane, equal to the per-client loop; a one-client
+    full-set group keeps one lane."""
+    model, train, _, specs = fleet_fl
+    cfg = FleetConfig(epochs=3, batch_size=16, lr=0.05, seed=0)
+    deadline = straggler_deadline(specs, cfg.epochs, 40.0)
+    budgets = nominal_budgets(specs, deadline, cfg.epochs)
+    groups = make_cohort_groups(train, list(range(len(specs))), budgets,
+                                cfg, round_seed=0)
+    engine = FleetEngine(model, cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    for coreset in (True, False):
+        g = next(g for g in groups if (g.k > 0) == coreset)
+        lone = dataclasses.replace(
+            g, cids=g.cids[:1], data=jax.tree.map(lambda v: v[:1], g.data),
+            valid=g.valid[:1], m=g.m[:1], perms=g.perms[:1])
+        _, args = engine.stage_group(params, lone)
+        assert jax.tree.leaves(args[2])[0].shape[0] == 1 + coreset
+        pb, lb, mb = engine.run_group(params, lone, batched=True)
+        pl, ll, ml = engine.run_group(params, lone, batched=False)
+        assert np.asarray(lb).shape == (1,)
+        for a, b in zip(jax.tree.leaves(pb), jax.tree.leaves(pl)):
+            assert a.shape[0] == 1
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
+        np.testing.assert_allclose(np.asarray(lb), np.asarray(ll),
+                                   atol=1e-5)
+        if coreset:
+            np.testing.assert_array_equal(np.asarray(mb), np.asarray(ml))
+
+
 class _ScriptedScheduler:
     """Minimal select/budget/observe/record_round scheduler driving a
     fixed per-round cohort script."""

@@ -145,6 +145,32 @@ def test_medoids_bit_identical_to_numpy_oracle(use_kernel, k):
         assert (np.asarray(res.assignment[c])[m:] == -1).all()
 
 
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_equal_cost_ties_break_to_the_lowest_index(use_kernel):
+    """Two pairs on a line, 0 1 ... 100 101, k = 2: the first BUILD pick
+    ties between 1 and 2, the second between 2 and 3, and no swap
+    improves.  Every solver path breaks such ties to the lowest index —
+    the f64 oracle by argmin order, the on-device solvers within
+    TIE_RTOL — so noise at the level of a reduction-order change (here a
+    1e-7 relative nudge towards the other candidate) cannot move a pick.
+    """
+    from repro.core.kmedoids import kmedoids_batched_from_feats
+    x = np.array([[0.0], [1.0], [100.0], [101.0]])
+    D = np.abs(x - x.T)
+    want = [1, 2]
+    assert sorted(np.asarray(kmedoids_numpy(D, 2).medoids)) == want
+    nudge = np.ones_like(D)
+    nudge[:, [2, 3]] -= 1e-7            # favour columns 2 and 3
+    Dn = jnp.asarray((D * nudge)[None].astype(np.float32))
+    valid = jnp.ones((1, 4), bool)
+    res = kmedoids_batched(Dn, valid, 2, use_kernel=use_kernel)
+    assert sorted(np.asarray(res.medoids[0])) == want
+    xn = jnp.asarray((x * (1 + 1e-7 * np.arange(4)[:, None]))[None]
+                     .astype(np.float32))
+    res = kmedoids_batched_from_feats(xn, valid, 2, use_kernel=use_kernel)
+    assert sorted(np.asarray(res.medoids[0])) == want
+
+
 def test_legacy_sweep_is_equivalent_baseline():
     """The pre-fusion minimum/one_hot/einsum chain (the selection
     benchmark's A/B baseline) picks identical medoids to the fused

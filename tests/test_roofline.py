@@ -30,12 +30,7 @@ def _measured_flops(cfg, batch, seq):
         return logits
 
     compiled = jax.jit(fwd).lower(params, batch_abs).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else None
-    if not ca:
-        pytest.skip("cost_analysis unavailable on this jax version")
-    return ca["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 @pytest.mark.parametrize("d_ff,vocab", [(512, 512), (1024, 2048)])
