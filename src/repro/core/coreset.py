@@ -31,6 +31,10 @@ class Coreset(NamedTuple):
     weights: jnp.ndarray   # (k,) float32 — δⁱ (cluster sizes)
     objective: jnp.ndarray  # scalar — the Eq.(5) k-medoids objective
     assignment: jnp.ndarray  # (m,) int32 — Φⁱ mapping (by medoid slot)
+    # the on-device solver's swap sweeps and, per lane, those that still
+    # swapped (``KMedoidsResult.sweeps`` / ``improving``; None otherwise)
+    sweeps: Optional[jnp.ndarray] = None
+    improving: Optional[jnp.ndarray] = None
 
 
 def coreset_budget(m: int, capability: float, deadline: float,
@@ -139,7 +143,8 @@ def build_coreset_batched(features: jnp.ndarray, valid: jnp.ndarray,
     return Coreset(indices=res.medoids,
                    weights=res.weights.astype(jnp.float32),
                    objective=res.objective,
-                   assignment=res.assignment)
+                   assignment=res.assignment,
+                   sweeps=res.sweeps, improving=res.improving)
 
 
 def coreset_epsilon(grads_full: jnp.ndarray, coreset: Coreset) -> jnp.ndarray:

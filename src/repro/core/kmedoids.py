@@ -70,6 +70,11 @@ class KMedoidsResult(NamedTuple):
     assignment: jnp.ndarray  # (m,) int32 index into [0, k)
     weights: jnp.ndarray     # (k,) int32 cluster sizes (the paper's δ)
     objective: jnp.ndarray   # scalar Σ_i min_k D[i, medoid_k]
+    # on-device solvers only (None from the numpy oracle): swap sweeps
+    # the while_loop ran (every lane runs as many as its slowest one) and
+    # how many of them still swapped a medoid, per lane
+    sweeps: Optional[jnp.ndarray] = None     # scalar int32
+    improving: Optional[jnp.ndarray] = None  # (C,) int32 (scalar if C = 1)
 
 
 # ---------------------------------------------------------------------------
@@ -197,11 +202,6 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
         # (d_near = +BIG for the first pick reduces it to the column sum)
         return kmedoids_build_cost(D, d_near, vf, use_kernel=use_kernel)
 
-    cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
-                                                      jnp.float32)))
-    first = _argmin_tied(cost0)                                    # (C,)
-    d_near0 = _take_col(D, first)
-
     def build_step(carry, _):
         d_near, chosen = carry
         cost = jnp.where(chosen | invalid, BIG, add_cost(d_near))
@@ -210,17 +210,22 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
         chosen = chosen | (iota_m[None] == nxt[:, None])
         return (d_near, chosen), nxt
 
-    mask0 = iota_m[None] == first[:, None]
-    if k > 1:
-        (_, _), rest = jax.lax.scan(build_step, (d_near0, mask0), None,
-                                    length=k - 1)
-        medoids0 = jnp.concatenate([first[:, None], rest.T], axis=1)
-    else:
-        medoids0 = first[:, None]
+    with jax.named_scope("build"):
+        cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
+                                                          jnp.float32)))
+        first = _argmin_tied(cost0)                                # (C,)
+        d_near0 = _take_col(D, first)
+        mask0 = iota_m[None] == first[:, None]
+        if k > 1:
+            (_, _), rest = jax.lax.scan(build_step, (d_near0, mask0), None,
+                                        length=k - 1)
+            medoids0 = jnp.concatenate([first[:, None], rest.T], axis=1)
+        else:
+            medoids0 = first[:, None]
 
     # ---- SWAP sweeps (FasterPAM Δ table; all reductions masked by vf) -----
     def sweep(state):
-        medoids, _, it = state
+        medoids, _, it, improved = state
         dm = jnp.take_along_axis(D, medoids[:, None, :], axis=2)  # (C, M, k)
         if legacy_sweep:
             # pre-fusion baseline: top_k stats + 3-pass minimum/one_hot/
@@ -256,15 +261,18 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
         delta = jnp.where((is_medoid | invalid)[..., None], BIG, delta)
         medoids, improves = _pick_swap(delta, medoids,
                                        jnp.sum(d1 * vf, axis=1))
-        return medoids, improves, it + 1
+        return (medoids, improves, it + 1,
+                improved + improves.astype(jnp.int32))
 
     def cond(state):
-        _, improves, it = state
+        _, improves, it, _ = state
         return jnp.any(improves) & (it < max_sweeps)
 
     state = (medoids0.astype(jnp.int32), jnp.ones((c,), bool),
-             jnp.asarray(0, jnp.int32))
-    medoids, _, _ = jax.lax.while_loop(cond, sweep, state)
+             jnp.asarray(0, jnp.int32), jnp.zeros((c,), jnp.int32))
+    with jax.named_scope("swap"):
+        medoids, _, sweeps, improving = jax.lax.while_loop(cond, sweep,
+                                                           state)
 
     dm = jnp.take_along_axis(D, medoids[:, None, :], axis=2)
     assignment = jnp.where(valid, jnp.argmin(dm, axis=-1),
@@ -272,7 +280,7 @@ def _kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
     weights = jnp.sum(jax.nn.one_hot(assignment, k, dtype=jnp.int32), axis=1)
     objective = jnp.sum(jnp.min(dm, axis=-1) * vf, axis=1)
     return KMedoidsResult(medoids.astype(jnp.int32), assignment, weights,
-                          objective)
+                          objective, sweeps, improving)
 
 
 def kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
@@ -293,7 +301,10 @@ def kmedoids_batched(D: jnp.ndarray, valid: jnp.ndarray, k: int,
     ``while_loop`` runs until every lane's swap phase converges; converged
     lanes are fixed points of the sweep (no Δ < −SWAP_RTOL·objective
     remains, so the masked update is the identity), hence each lane's
-    result equals its standalone ``kmedoids_masked`` solve.
+    result equals its standalone ``kmedoids_masked`` solve.  ``sweeps``
+    counts the loop's sweeps and ``improving`` (C,) the sweeps in which
+    each lane still swapped: a lane converged after BUILD reads 0, and
+    ``sweeps − improving`` is the work spent on a lane already converged.
 
     ``use_kernel`` is the tri-state Pallas switch (None = auto: kernels on
     TPU, jnp elsewhere — see ``repro.kernels.ops.resolve_use_kernel``);
@@ -359,11 +370,6 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
         return kmedoids_build_cost_from_feats(xf, d_near, vf,
                                               use_kernel=use_kernel)
 
-    cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
-                                                      jnp.float32)))
-    first = _argmin_tied(cost0)                                    # (C,)
-    d_near0 = _col_dists(xf, sq, first)
-
     def build_step(carry, _):
         d_near, chosen = carry
         cost = jnp.where(chosen | invalid, BIG, add_cost(d_near))
@@ -372,18 +378,23 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
         chosen = chosen | (iota_m[None] == nxt[:, None])
         return (d_near, chosen), nxt
 
-    mask0 = iota_m[None] == first[:, None]
-    if k > 1:
-        (_, _), rest = jax.lax.scan(build_step, (d_near0, mask0), None,
-                                    length=k - 1)
-        medoids0 = jnp.concatenate([first[:, None], rest.T], axis=1)
-    else:
-        medoids0 = first[:, None]
+    with jax.named_scope("build"):
+        cost0 = jnp.where(invalid, BIG, add_cost(jnp.full((c, m), BIG,
+                                                          jnp.float32)))
+        first = _argmin_tied(cost0)                                # (C,)
+        d_near0 = _col_dists(xf, sq, first)
+        mask0 = iota_m[None] == first[:, None]
+        if k > 1:
+            (_, _), rest = jax.lax.scan(build_step, (d_near0, mask0), None,
+                                        length=k - 1)
+            medoids0 = jnp.concatenate([first[:, None], rest.T], axis=1)
+        else:
+            medoids0 = first[:, None]
 
     # ---- SWAP: d1/d2/n(i) come from the O(C·M·k) medoid-distance slab;
     # the Δ reductions stream feature tiles.
     def sweep(state):
-        medoids, _, it = state
+        medoids, _, it, improved = state
         dm = _medoid_dists(xf, sq, medoids)                       # (C, M, k)
         d1 = jnp.min(dm, axis=-1)
         n_idx = jnp.argmin(dm, axis=-1).astype(jnp.int32)
@@ -398,15 +409,18 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
         delta = jnp.where((is_medoid | invalid)[..., None], BIG, delta)
         medoids, improves = _pick_swap(delta, medoids,
                                        jnp.sum(d1 * vf, axis=1))
-        return medoids, improves, it + 1
+        return (medoids, improves, it + 1,
+                improved + improves.astype(jnp.int32))
 
     def cond(state):
-        _, improves, it = state
+        _, improves, it, _ = state
         return jnp.any(improves) & (it < max_sweeps)
 
     state = (medoids0.astype(jnp.int32), jnp.ones((c,), bool),
-             jnp.asarray(0, jnp.int32))
-    medoids, _, _ = jax.lax.while_loop(cond, sweep, state)
+             jnp.asarray(0, jnp.int32), jnp.zeros((c,), jnp.int32))
+    with jax.named_scope("swap"):
+        medoids, _, sweeps, improving = jax.lax.while_loop(cond, sweep,
+                                                           state)
 
     dm = _medoid_dists(xf, sq, medoids)
     assignment = jnp.where(valid, jnp.argmin(dm, axis=-1),
@@ -414,7 +428,7 @@ def _kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
     weights = jnp.sum(jax.nn.one_hot(assignment, k, dtype=jnp.int32), axis=1)
     objective = jnp.sum(jnp.min(dm, axis=-1) * vf, axis=1)
     return KMedoidsResult(medoids.astype(jnp.int32), assignment, weights,
-                          objective)
+                          objective, sweeps, improving)
 
 
 def kmedoids_batched_from_feats(feats: jnp.ndarray, valid: jnp.ndarray,
@@ -451,7 +465,7 @@ def kmedoids_masked(D: jnp.ndarray, valid: jnp.ndarray, k: int,
     ``kmedoids_batched`` (one solver, one copy of the Δ-table math)."""
     res = kmedoids_batched(D[None], valid[None], k, max_sweeps, use_kernel)
     return KMedoidsResult(res.medoids[0], res.assignment[0], res.weights[0],
-                          res.objective[0])
+                          res.objective[0], res.sweeps, res.improving[0])
 
 
 def kmedoids_jax(D: jnp.ndarray, k: int, max_sweeps: int = 50,

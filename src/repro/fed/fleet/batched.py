@@ -42,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +128,14 @@ class CohortGroup:
     @property
     def n_clients(self) -> int:
         return len(self.cids)
+
+
+class GroupSelection(NamedTuple):
+    """A coreset group program's selection output: the medoids, and the
+    swap-sweep counts that the ``gather`` span reads when recording."""
+    medoids: jnp.ndarray          # (C, k) selected sample indices
+    sweeps: jnp.ndarray           # () swap sweeps the group's solve ran
+    improving: jnp.ndarray        # (C,) sweeps in which each lane swapped
 
 
 @dataclasses.dataclass
@@ -374,10 +382,15 @@ class FleetEngine:
         selection, one full-set epoch, E−1 weighted coreset epochs.
         Signature (k == 0): ``body(params, p0, data, w, idx)``;
         (k > 0): ``body(params, p0, data, w, valid, idx1, steps)``; both
-        return ``(params (C, ...), losses (C,), medoid indices or
-        None)``.  ``p0`` is the pre-broadcast (C, ...) parameter stack —
-        passed in (rather than built inside) so the jitted wrapper can
-        donate its buffers to the same-shaped output stack.  The sharded
+        return ``(params (C, ...), losses (C,), GroupSelection or
+        None)``.  Each phase runs under a ``jax.named_scope`` of its
+        ``repro.obs.schema.PHASES`` name (``grad_features``,
+        ``selection``, ``local_sgd``, ``coreset_epochs``), which names
+        its operations in the compiled program's ``op_name`` metadata and
+        changes nothing else.  ``p0`` is the pre-broadcast (C, ...)
+        parameter stack — passed in (rather than built inside) so the
+        jitted wrapper can donate its buffers to the same-shaped output
+        stack.  The sharded
         engine wraps this exact body in ``shard_map`` (per-device client
         lanes + psum aggregation), which is what keeps the loop / batched
         / sharded parity contract a single copy of the arithmetic.
@@ -392,22 +405,28 @@ class FleetEngine:
 
         if k == 0:
             def body(params, p0, data, w, idx):
-                p, losses = vm_sgd(p0, data, w, idx)
+                with jax.named_scope("local_sgd"):
+                    p, losses = vm_sgd(p0, data, w, idx)
                 return p, losses, None
             return body
 
         def body(params, p0, data, w, valid, idx1, steps):
-            feats = vm_feats(params, data)                 # (C, M, F)
-            coreset = build_coreset_batched(
-                feats, valid, k, use_kernel=cfg.use_kernel,
-                max_sweeps=cfg.max_sweeps,
-                distance_free=cfg.distance_free,
-                materialize_below=cfg.materialize_below)
-            p, _ = vm_sgd(p0, data, w, idx1)
-            cdata = jax.tree.map(
-                lambda v: vm_gather(v, coreset.indices), data)  # (C, k, ...)
-            p, losses = vm_core(p, cdata, coreset.weights, steps)
-            return p, losses, coreset.indices
+            with jax.named_scope("grad_features"):
+                feats = vm_feats(params, data)             # (C, M, F)
+            with jax.named_scope("selection"):
+                coreset = build_coreset_batched(
+                    feats, valid, k, use_kernel=cfg.use_kernel,
+                    max_sweeps=cfg.max_sweeps,
+                    distance_free=cfg.distance_free,
+                    materialize_below=cfg.materialize_below)
+            with jax.named_scope("local_sgd"):
+                p, _ = vm_sgd(p0, data, w, idx1)
+            with jax.named_scope("coreset_epochs"):
+                cdata = jax.tree.map(                      # (C, k, ...)
+                    lambda v: vm_gather(v, coreset.indices), data)
+                p, losses = vm_core(p, cdata, coreset.weights, steps)
+            return p, losses, GroupSelection(coreset.indices, coreset.sweeps,
+                                             coreset.improving)
         return body
 
     @staticmethod
@@ -513,24 +532,26 @@ class FleetEngine:
 
     def _run_group_stacked(self, params: Pytree, group: CohortGroup
                            ) -> Tuple[Pytree, jnp.ndarray,
-                                      Optional[jnp.ndarray]]:
+                                      Optional[GroupSelection]]:
         """Run a group as ONE jitted dispatch; returns (params (C,...),
-        losses, medoid indices or None)."""
+        losses, ``GroupSelection`` or None)."""
         # asarray never changes the treedef, so the program cache can be
         # consulted before staging — letting the dispatch span charge the
-        # host-side transfers to the phase they belong to
+        # host-side transfers to the phase they belong to (the copies in
+        # a ``stage`` span of their own, see ``_group_args``)
         program = self._group_program(group.k,
                                       jax.tree.structure(group.data))
         self.count_dispatch()
         name = "local_sgd" if group.k == 0 else "coreset_group"
         c = group.n_clients
         with self._dispatch_span(name, program, k=group.k, n_clients=c):
-            p, losses, meds = program(*self._group_args(params, group))
+            p, losses, sel = program(*self._group_args(params, group))
             if losses.shape[0] != c:        # a lone client's second lane
                 p = jax.tree.map(lambda x: x[:c], p)
                 losses = losses[:c]
-                meds = None if meds is None else meds[:c]
-        return p, losses, meds
+                sel = None if sel is None else sel._replace(
+                    medoids=sel.medoids[:c], improving=sel.improving[:c])
+        return p, losses, sel
 
     @staticmethod
     def _lanes(group: CohortGroup):
@@ -546,23 +567,40 @@ class FleetEngine:
         return [0, 0] if group.n_clients == 1 and group.k else slice(None)
 
     def _group_args(self, params: Pytree, group: CohortGroup) -> Tuple:
-        """Device arguments of the group program, one device transfer per
-        array.  ``k == 0``: E epochs of minibatch SGD.  ``k > 0``: the
-        Alg. 1 straggler path — features at round-start params, fused
-        coreset selection, one full-set epoch, E−1 coreset epochs — all
-        inside the one program."""
+        """Device arguments of the group program.  ``k == 0``: E epochs
+        of minibatch SGD.  ``k > 0``: the Alg. 1 straggler path —
+        features at round-start params, fused coreset selection, one
+        full-set epoch, E−1 coreset epochs — all inside the one program.
+
+        The host-to-device copies of data, masks and minibatch indices,
+        one per array, run first, in a ``stage`` span whose ``bytes``
+        (counted only when recording) are what they copy.  The
+        parameters' lane broadcast and the coreset epochs' step buffer
+        are made on the device after it: eager dispatches, which block
+        the host while the device's queue of executions is full, so
+        that wait falls outside ``stage`` and just before the group's
+        own dispatch."""
         lanes = self._lanes(group)
-        data = jax.tree.map(lambda v: jnp.asarray(v[lanes]), group.data)
         valid = group.valid[lanes]                              # (C, M)
         c = valid.shape[0]
-        w = jnp.asarray(valid.astype(np.float32))
+        obs = get_recorder()
+        with obs.span("stage") as sp:
+            data = jax.tree.map(lambda v: jnp.asarray(v[lanes]), group.data)
+            w = jnp.asarray(valid.astype(np.float32))
+            if group.k == 0:
+                copied = (data, w,
+                          self._batch_indices(group, slice(None), lanes))
+            else:
+                copied = (data, w, jnp.asarray(valid),
+                          self._batch_indices(group, slice(0, 1), lanes))
+            if obs.enabled:
+                sp.attrs["bytes"] = sum(int(leaf.nbytes) for leaf
+                                        in jax.tree.leaves(copied))
         p0 = self._broadcast_params(params, c)
         if group.k == 0:
-            idx = self._batch_indices(group, slice(None), lanes)
-            return params, p0, data, w, idx
-        idx1 = self._batch_indices(group, slice(0, 1), lanes)
-        steps = jnp.zeros((c, max(self.cfg.epochs - 1, 1)))
-        return params, p0, data, w, jnp.asarray(valid), idx1, steps
+            return (params, p0) + copied
+        return (params, p0) + copied + (
+            jnp.zeros((c, max(self.cfg.epochs - 1, 1))),)
 
     def stage_group(self, params: Pytree, group: CohortGroup
                     ) -> Tuple[Any, Tuple]:
@@ -619,8 +657,11 @@ class FleetEngine:
         return p, float(loss), med
 
     def run_group(self, params: Pytree, group: CohortGroup,
-                  batched: bool = True) -> Tuple[Pytree, np.ndarray,
-                                                 Optional[np.ndarray]]:
+                  batched: bool = True) -> Tuple[Pytree, np.ndarray, Any]:
+        """(params (C, ...), losses (C,), selection): the selection is
+        None for a k = 0 group, else the batched program's
+        ``GroupSelection`` (medoids and swap-sweep counts) or the loop's
+        stacked (C, k) medoids."""
         if batched:
             # losses and medoids stay on the device: converting here would
             # wait for this group before the next one is dispatched (the
@@ -640,6 +681,30 @@ class FleetEngine:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *ps)
         return (stacked, np.array(losses),
                 np.stack(meds) if meds else None)
+
+
+def _sweep_attrs(counts: List[Tuple[Any, Any]]) -> Dict[str, int]:
+    """``gather`` span attributes from each coreset group's (swap sweeps,
+    per-lane improving sweeps): the sweeps summed over groups, and over
+    lanes the sweeps run and those that still improved."""
+    sweeps = [int(n) for n, _ in counts]
+    lanes = [np.asarray(imp) for _, imp in counts]
+    return {"swap_sweeps": sum(sweeps),
+            "lane_sweeps_run": sum(n * imp.size
+                                   for n, imp in zip(sweeps, lanes)),
+            "lane_sweeps_improving": int(sum(imp.sum() for imp in lanes))}
+
+
+def _stacked_bytes(params: Pytree, trees: Sequence[Pytree]) -> int:
+    """Summed ``nbytes`` of parameter-shaped trees, each the parameters
+    or a stack of them along a leading axis: the parameters' bytes times
+    each tree's stack depth, read off its first leaf."""
+    leaves = jax.tree.leaves(params)
+    if not leaves:
+        return 0
+    nbytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    return sum(nbytes * (jax.tree.leaves(t)[0].size // leaves[0].size)
+               for t in trees)
 
 
 def weighted_param_sum(stacked: Pytree, weights) -> Pytree:
@@ -774,13 +839,12 @@ def run_fleet_round(engine: FleetEngine, params: Pytree,
         all_meds.append(meds)       # every group has been dispatched
         all_drop.append(drop)
         all_corrupt.append(corrupt & ~drop)   # a lost update corrupts nothing
+    if obs.enabled:     # bytes entering the reduction, outside its span
+        src = partials if not needs_stack else stacks
+        obs.metrics.counter("aggregate.bytes").inc(
+            _stacked_bytes(params, [entry[0] for entry in src]))
     with obs.span("aggregate", n_groups=len(groups),
                   aggregator=aggregator):
-        if obs.enabled:             # bytes entering the reduction
-            src = partials if not needs_stack else stacks
-            obs.metrics.counter("aggregate.bytes").inc(sum(
-                int(leaf.nbytes) for entry in src
-                for leaf in jax.tree.leaves(entry[0])))
         if needs_stack:
             trees, wlist = [], []
             for stack, w_eff, drop in stacks:
@@ -802,13 +866,20 @@ def run_fleet_round(engine: FleetEngine, params: Pytree,
             new_params = engine.combine_group_sums(partials, fallback=params)
         else:
             new_params = _aggregate_groups(partials, fallback=params)
-    with obs.span("gather", n_groups=len(groups)):
+    with obs.span("gather", n_groups=len(groups)) as sp:
         all_loss = [np.asarray(ls) for ls in all_loss]
+        counts = []
         for g, meds in zip(groups, all_meds):
+            if isinstance(meds, GroupSelection):
+                if obs.enabled:     # read back only when recording
+                    counts.append((meds.sweeps, meds.improving))
+                meds = meds.medoids
             if meds is not None:
                 meds = np.asarray(meds)
                 for cid, med in zip(g.cids, meds):
                     medoids[int(cid)] = med
+        if counts:
+            sp.attrs.update(_sweep_attrs(counts))
     stats = FleetRoundStats(
         cids=_cat(all_cids, np.int64), m=_cat(all_m, np.int64),
         budgets=_cat(all_b, np.int64),

@@ -138,10 +138,10 @@ class ShardedFleetEngine(FleetEngine):
         else:
             def body(params, data, w, lane_w, idx1, valid, steps):
                 c = w.shape[0]
-                p, losses, meds = group_body(params, broadcast(params, c),
-                                             data, w, valid, idx1, steps)
+                p, losses, sel = group_body(params, broadcast(params, c),
+                                            data, w, valid, idx1, steps)
                 part, wsum = weighted_psum_sum(lane_w, p, axes)
-                return part, wsum, losses, meds, p
+                return part, wsum, losses, sel.medoids, p
 
             def specs(params):
                 shard = P(CLIENT_AXIS)

@@ -64,7 +64,7 @@ RUNTIMES = ("sync", "async", "fleet", "async_fleet")
 # first appearance, so this is documentation + test reference, not a gate)
 PHASES = ("cohort_build", "cohort_select", "local_update", "local_sgd",
           "grad_features", "distances", "selection", "coreset_group",
-          "coreset_epochs", "dispatch", "gather", "aggregate",
+          "coreset_epochs", "dispatch", "stage", "gather", "aggregate",
           "trace_account", "eval", "buffer_fill", "dispatch_wave",
           "checkpoint")
 

@@ -212,7 +212,8 @@ def test_lone_coreset_client_runs_as_two_lanes(fleet_fl):
         np.testing.assert_allclose(np.asarray(lb), np.asarray(ll),
                                    atol=1e-5)
         if coreset:
-            np.testing.assert_array_equal(np.asarray(mb), np.asarray(ml))
+            np.testing.assert_array_equal(np.asarray(mb.medoids),
+                                          np.asarray(ml))
 
 
 class _ScriptedScheduler:

@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from conftest import fixed_size_clients
-from repro.core.kmedoids import (kmedoids_batched, kmedoids_numpy,
+from repro.core.kmedoids import (kmedoids_batched,
+                                 kmedoids_batched_from_feats, kmedoids_numpy,
                                  pairwise_sq_dists)
 from repro.fed.fleet.batched import (FleetConfig, FleetEngine,
                                      make_cohort_groups, run_fleet_round)
@@ -245,10 +246,10 @@ def test_fused_group_program_is_single_dispatch():
     engine._sgd_step1 = engine._core_step1 = None
 
     before = engine.dispatch_count
-    p, losses, meds = engine.run_group(params, g, batched=True)
+    p, losses, sel = engine.run_group(params, g, batched=True)
     assert len(calls) == 1
     assert engine.dispatch_count - before == 1
-    assert meds is not None and meds.shape == (g.n_clients, g.k)
+    assert sel is not None and sel.medoids.shape == (g.n_clients, g.k)
     assert np.isfinite(losses).all()
 
 
@@ -341,3 +342,35 @@ def test_fleet_config_replace_keeps_frozen_contract():
     cfg = FleetConfig(epochs=3, use_kernel=None)
     on = dataclasses.replace(cfg, use_kernel=True)
     assert on.use_kernel is True and on.epochs == 3
+
+
+@pytest.mark.parametrize("solver", ["distances", "features"])
+def test_swap_sweep_counts(solver):
+    """The solvers count their swap sweeps and, per lane, the sweeps that
+    still swapped: every lane runs the group's sweeps, a lane already at
+    a fixed point after BUILD counts 0, and a lane's count is its own
+    solve's (alone it runs them plus the sweep that finds no swap)."""
+    rng = np.random.default_rng(0)
+    m, k = 48, 5
+    x = rng.normal(size=(2, m, 6)).astype(np.float32)
+    valid = np.ones((2, m), bool)
+    valid[1, k:] = False            # lane 1: BUILD takes every valid point
+    x[1, k:] = 0.0
+
+    def solve(x, valid):
+        if solver == "features":
+            return kmedoids_batched_from_feats(jnp.asarray(x),
+                                               jnp.asarray(valid), k)
+        D = np.sqrt(((x[:, :, None] - x[:, None]) ** 2).sum(-1))
+        return kmedoids_batched(jnp.asarray(D, jnp.float32),
+                                jnp.asarray(valid), k)
+
+    res = solve(x, valid)
+    sweeps, improving = int(res.sweeps), np.asarray(res.improving)
+    assert improving.shape == (2,)
+    assert (improving <= sweeps).all()
+    assert improving[0] >= 1 and improving[1] == 0
+    alone = solve(x[:1], valid[:1])
+    assert int(alone.sweeps) == improving[0] + 1 == sweeps
+    np.testing.assert_array_equal(np.asarray(alone.medoids[0]),
+                                  np.asarray(res.medoids[0]))

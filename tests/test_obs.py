@@ -222,10 +222,11 @@ def test_recording_preserves_determinism(small_fleet, runtime, engine):
     vs off: recording never touches event ordering, RNG, or numerics."""
     wl, train, test = small_fleet
     kw = {"fleet_engine": engine} if engine else {}
+    sink = InMemorySink()
 
     def go(record):
         if record:
-            return _run(runtime, wl, train, test, [InMemorySink()], **kw)
+            return _run(runtime, wl, train, test, [sink], **kw)
         return run_scenario("device_classes", runtime, clients_data=train,
                             test_data=test, workload=wl, seed=0, rounds=2,
                             epochs=2, batch_size=8, **kw)
@@ -247,6 +248,77 @@ def test_recording_preserves_determinism(small_fleet, runtime, engine):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     if runtime in ("async", "async_fleet"):
         assert on["event_log"] == off["event_log"]
+    if (runtime, engine) == ("fleet", "batched"):
+        # the sweep counts the gather span reads back only when recording
+        counts = [r["attrs"] for r in sink.records if r["kind"] == "span"
+                  and r["name"] == "gather" and "swap_sweeps" in r["attrs"]]
+        assert counts
+        for c in counts:
+            assert c["swap_sweeps"] >= 1
+            assert c["lane_sweeps_run"] >= c["lane_sweeps_improving"] >= 0
+
+
+def test_stage_span_in_each_dispatch_span(small_fleet):
+    """Each group dispatch holds one ``stage`` span, and its ``bytes`` are
+    the arguments copied from the host: data, masks and minibatch indices
+    at their device dtypes (the parameters are on the device already)."""
+    from repro.fed.fleet.batched import (FleetConfig, FleetEngine,
+                                         make_cohort_groups, run_fleet_round)
+    from repro.fed.fleet.workloads import client_num_samples
+    wl, train, _ = small_fleet
+    cfg = FleetConfig(epochs=2, batch_size=8, seed=0)
+    engine = FleetEngine(wl, cfg)
+    params = wl.init(jax.random.PRNGKey(0))
+    cids = list(range(len(train)))
+    budgets = {c: 9 if c % 2 else client_num_samples(train[c]) for c in cids}
+    groups = make_cohort_groups(train, cids, budgets, cfg, 0)
+    assert {g.k > 0 for g in groups} == {False, True}
+    sink = InMemorySink()
+    with use_recorder(Recorder([sink])):
+        run_fleet_round(engine, params, train, cids, budgets, groups=groups)
+    spans = [r for r in sink.records if r["kind"] == "span"]
+    dispatch = [s for s in spans
+                if s["name"] in ("local_sgd", "coreset_group")]
+    assert len(dispatch) == len(groups)
+
+    def device_bytes(v):
+        return v.size * np.dtype(jax.dtypes.canonicalize_dtype(
+            v.dtype)).itemsize
+
+    for g, d in zip(groups, dispatch):
+        (stage,) = [s for s in spans
+                    if s["name"] == "stage" and s["parent"] == d["sid"]]
+        c, m = g.valid.shape
+        want = sum(device_bytes(v) for v in jax.tree.leaves(g.data))
+        want += 4 * c * m                                   # loss weights
+        if g.k == 0:
+            want += 4 * c * cfg.epochs * m                  # E epochs' indices
+        else:
+            want += c * m + 4 * c * m                        # mask, epoch 0
+        assert stage["attrs"]["bytes"] == want
+
+
+@pytest.mark.parametrize("aggregator", ["weighted_mean", "median"])
+def test_aggregate_bytes_counts_one_update_per_client(small_fleet,
+                                                      aggregator):
+    """``aggregate.bytes`` is the bytes of the parameter stacks entering
+    the reduction, group partials or robust stacks alike: one copy of
+    the parameters per client of the cohort."""
+    from repro.fed.fleet.batched import (FleetConfig, FleetEngine,
+                                         run_fleet_round)
+    from repro.fed.fleet.workloads import client_num_samples
+    wl, train, _ = small_fleet
+    engine = FleetEngine(wl, FleetConfig(epochs=2, batch_size=8, seed=0))
+    params = wl.init(jax.random.PRNGKey(0))
+    cids = list(range(len(train)))
+    budgets = {c: 9 if c % 2 else client_num_samples(train[c]) for c in cids}
+    rec = Recorder([InMemorySink()])
+    with use_recorder(rec):
+        run_fleet_round(engine, params, train, cids, budgets,
+                        aggregator=aggregator)
+    per_client = sum(int(x.nbytes) for x in jax.tree.leaves(params))
+    assert (rec.metrics.snapshot()["counters"]["aggregate.bytes"]
+            == len(cids) * per_client)
 
 
 # ---------------------------------------------------------------------------
