@@ -152,6 +152,71 @@ class SmallCNN:
 # LSTM char-LM (Shakespeare benchmark)
 # ---------------------------------------------------------------------------
 
+def _lstm_scan(wh, b, xw):
+    """The recurrence over time-major input projections.
+
+    xw (S, B, 4H) holds ``x_t @ wx`` for every step; gates (i, f, g, o)
+    with the forget gate's +1.  Returns hs (S, B, H) and the per-step
+    activations the backward reads: the four gates, c and tanh(c).
+    """
+    h0 = jnp.zeros((xw.shape[1], wh.shape[0]), xw.dtype)
+
+    def step(carry, xw_t):
+        h, c = carry
+        zi, zf, zg, zo = jnp.split(xw_t + h @ wh + b, 4, axis=-1)
+        i, f = jax.nn.sigmoid(zi), jax.nn.sigmoid(zf + 1.0)
+        g, o = jnp.tanh(zg), jax.nn.sigmoid(zo)
+        c = f * c + i * g
+        tc = jnp.tanh(c)
+        h = o * tc
+        return (h, c), (h, (i, f, g, o, c, tc))
+
+    _, (hs, acts) = jax.lax.scan(step, (h0, h0), xw)
+    return hs, acts
+
+
+@jax.custom_vjp
+def lstm_recurrence(wh, b, xw):
+    """LSTM layer's recurrence: (wh (H, 4H), b (4H,), xw (S, B, 4H)) ->
+    hs (S, B, H).  The backward carries only (dh, dc) through time and
+    forms the weight gradients after the loop, as contractions over all
+    S·B rows at once, instead of accumulating them step by step."""
+    return _lstm_scan(wh, b, xw)[0]
+
+
+def _lstm_recurrence_fwd(wh, b, xw):
+    hs, acts = _lstm_scan(wh, b, xw)
+    return hs, (wh, hs, acts)
+
+
+def _lstm_recurrence_bwd(res, dhs):
+    wh, hs, (i, f, g, o, cs, tcs) = res
+    zero = jnp.zeros_like(hs[:1])
+    h_prev = jnp.concatenate([zero, hs[:-1]])
+    c_prev = jnp.concatenate([zero, cs[:-1]])
+
+    # the derivatives as JAX's own rules write them: logistic s(1 - s),
+    # tanh (1 + t)(1 - t)
+    def step(carry, xs):
+        dh, dc = carry
+        dh_t, i, f, g, o, cp, tc = xs
+        dh = dh + dh_t
+        dc = dc + dh * o * ((1 + tc) * (1 - tc))
+        dz = jnp.concatenate([dc * g * (i * (1 - i)),
+                              dc * cp * (f * (1 - f)),
+                              dc * i * ((1 + g) * (1 - g)),
+                              dh * tc * (o * (1 - o))], axis=-1)
+        return (dz @ wh.T, dc * f), dz
+
+    _, dz = jax.lax.scan(step, (zero[0], zero[0]),
+                         (dhs, i, f, g, o, c_prev, tcs), reverse=True)
+    dwh = jnp.einsum("sbh,sbg->hg", h_prev, dz)
+    return dwh, jnp.sum(dz, axis=(0, 1)), dz
+
+
+lstm_recurrence.defvjp(_lstm_recurrence_fwd, _lstm_recurrence_bwd)
+
+
 @dataclasses.dataclass(frozen=True)
 class CharLSTM:
     vocab: int = 80
@@ -180,27 +245,15 @@ class CharLSTM:
         return params
 
     def _lstm_layer(self, p, x):
-        """x: (B, S, D) -> (B, S, H)."""
-        b = x.shape[0]
-        h0 = jnp.zeros((b, self.d_hidden))
-        c0 = jnp.zeros((b, self.d_hidden))
-
-        def step(carry, x_t):
-            h, c = carry
-            gates = x_t @ p["wx"] + h @ p["wh"] + p["b"]
-            i, f, g, o = jnp.split(gates, 4, axis=-1)
-            c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-            h = jax.nn.sigmoid(o) * jnp.tanh(c)
-            return (h, c), h
-
-        (_, _), hs = jax.lax.scan(step, (h0, c0), x.transpose(1, 0, 2))
-        return hs.transpose(1, 0, 2)
+        """x: (S, B, D) time-major -> (S, B, H)."""
+        # every step's input projection in one matmul, off the serial loop
+        return lstm_recurrence(p["wh"], p["b"], x @ p["wx"])
 
     def hidden(self, params, tokens):
-        x = params["embed"][tokens]
+        x = params["embed"][tokens.T]                      # (S, B, D)
         for i in range(self.n_layers):
             x = self._lstm_layer(params[f"lstm{i}"], x)
-        return x
+        return x.transpose(1, 0, 2)                        # (B, S, H)
 
     def logits(self, params, tokens):
         return self.hidden(params, tokens) @ params["w_out"] + params["b_out"]

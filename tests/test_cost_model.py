@@ -197,7 +197,9 @@ def test_unit_conversions():
 GOLDEN_FLOPS_PER_SAMPLE = {
     "mlp": 2.66e3,
     "cnn": 8.59e5,
-    "charlm": 2.42e5,
+    # a while body is counted once: the LSTM's hoisted matmuls count in
+    # full, those left in its time loop for one step
+    "charlm": 4.63e5,
     "xlstm": 7.53e5,
     "translm": 1.36e6,
 }

@@ -13,13 +13,16 @@ compilation cache is off around the compiles: a cache entry compiled for
 a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.fed.fleet.batched import FleetConfig, FleetEngine
 from repro.kernels import ops
+from repro.models.small import CharLSTM
 
 
 @pytest.fixture(scope="module")
@@ -113,3 +116,61 @@ def test_flash_attention_compiles(one_chip):
         lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
                                             interpret=False),
         one_chip, shape, shape, shape)
+
+
+def _hlo_computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name, comps[head.group(1)] = head.group(1), []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _while_bodies(lines):
+    return [re.search(r"body=%?([\w.\-]+)", l).group(1) for l in lines
+            if re.search(r" while\(", l)]
+
+
+def test_lstm_weight_gradients_leave_the_time_loop(one_chip):
+    """A 2-lane SGD scan of the Shakespeare LSTM (H 256, S 80, B 32):
+    no time loop nested in the SGD loop computes an f32[C, H, 4H] value.
+    Autodiff of a scan that closes over its weights accumulates dWh (and
+    dWx) there at every step; the recurrence's own VJP forms them once,
+    after the loop."""
+    c, m, s, b, t = 2, 64, 80, 32, 4
+    model = CharLSTM(vocab=80, d_embed=8, d_hidden=256, n_layers=2)
+    engine = FleetEngine(model, FleetConfig(batch_size=b))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p0 = jax.tree.map(lambda a: arg((c,) + a.shape, a.dtype), params)
+    data = {"x": arg((c, m, s), jnp.int32), "y": arg((c, m, s), jnp.int32)}
+    text = jax.jit(jax.vmap(engine._sgd_scan)).lower(
+        p0, data, arg((c, m), F32), arg((c, t, b), jnp.int32)
+    ).compile().as_text()
+    comps = _hlo_computations(text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    sgd_loops = [w for w in _while_bodies(comps[entry])
+                 if _while_bodies(comps[w])]
+    assert len(sgd_loops) == 1
+    # forward and backward time loop of each of the two layers
+    time_loops = _while_bodies(comps[sgd_loops[0]])
+    assert len(time_loops) == 4
+    weight_grad = f"f32[{c},256,1024]"
+    computed = []
+    for body in time_loops:
+        for line in comps[body]:
+            name, rhs = line.split(" = ", 1)
+            op = re.search(r" ([a-z][\w\-]*)\(", rhs)
+            if (weight_grad in rhs[:op.start()] and op.group(1) not in
+                    ("parameter", "get-tuple-element", "tuple")):
+                computed.append(name)
+    assert computed == []
